@@ -1440,6 +1440,8 @@ def main(argv=None):
     ap.add_argument("--only", default="")
     args = ap.parse_args(argv)
     _force_multi_device(8)
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     sections = {
         "table1": bench_table1_mag,

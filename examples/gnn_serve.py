@@ -31,6 +31,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     import jax
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     from repro.core import HIDDEN_STATE, mag_schema
     from repro.core.models import vanilla_mpnn
     from repro.data import SamplingSpecBuilder

@@ -27,6 +27,11 @@ state shrunk by the data factor:
         python examples/ogbn_mag_train.py --steps 3 --num-devices 8 \\
         --model-parallel 2
 
+On TPU the loss matches only with fp32 matmuls: at the default
+precision an f32 matmul rounds its operands to bf16, so each model
+shard's partial cotangent is rounded on its own before the shards are
+summed, and the loss drifts by about 1e-3 relative within three steps.
+
 With ``--sampler service`` the training stream comes from the async
 sampling service instead (repro.sampling_service): a fleet of sampler
 worker processes runs Algorithm 1 + merge + pad off the training host
@@ -37,9 +42,10 @@ double-buffered onto the mesh.  Same plan, same per-root sampling seeds
     XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \\
         python examples/ogbn_mag_train.py --sampler service --num-devices 8
 
-``--multihost N`` crosses the process boundary: the script relaunches
-itself as N `jax.distributed` processes (each contributing
-``num_devices / N`` local devices to one GLOBAL mesh), process 0
+``--multihost N`` crosses the process boundary — a CPU rehearsal of a
+multi-host job on one host: the script relaunches itself as N
+`jax.distributed` processes (each contributing ``num_devices / N``
+forced CPU devices to one GLOBAL mesh), process 0
 additionally hosts a `SamplerEndpoint` whose per-rank `SamplingService`
 fleets stream every rank's batches over TCP (`RemoteStreamClient` with
 reconnect + resume-from-watermark), and the per-process rank shards are
@@ -50,7 +56,9 @@ mesh => the same loss as the single-process run of the same size:
         --num-devices 4 --multihost 2
 
 Per-rank logs land in ``--multihost-log-dir`` (the CI smoke job uploads
-them as artifacts).  Ports are OS-assigned; the coordinator address and
+them as artifacts).  On a host with TPU chips ``--multihost`` refuses to
+start: every child would try to take every chip, and a chip belongs to
+one process.  Ports are OS-assigned; the coordinator address and
 the endpoint address travel to the children via environment / a shared
 address file, never fixed port numbers.
 """
@@ -111,6 +119,14 @@ def _launch_multihost(args) -> int:
     """Parent mode: spawn --multihost N child processes of this very
     command line (children are marked by REPRO_PROCESS_ID), harvest
     their per-rank logs, and propagate failure.  Never imports jax."""
+    from repro.runtime import tpu_chips_attached
+    chips = tpu_chips_attached()
+    if chips:
+        raise SystemExit(
+            f"--multihost is a CPU rehearsal of multi-host training, and "
+            f"this host has {chips} TPU chip(s): each of the {args.multihost} "
+            f"children would try to take all of them.  Run one process "
+            f"per host (--num-devices N uses every local chip).")
     nproc = args.multihost
     if args.num_devices % nproc:
         raise SystemExit(f"--multihost {nproc} must divide "
@@ -166,6 +182,10 @@ if args.multihost > 1 and "REPRO_PROCESS_ID" not in os.environ:
     raise SystemExit(_launch_multihost(args))
 
 import jax
+
+from repro.runtime import enable_compile_cache
+
+enable_compile_cache()
 
 from repro.core import HIDDEN_STATE, mag_schema
 from repro.core.models import vanilla_mpnn

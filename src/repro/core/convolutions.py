@@ -10,6 +10,7 @@ the pooled message tensor shaped like a feature of the receiver set.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable, Optional
 
 import jax
@@ -183,6 +184,31 @@ class SimpleConv(AnyToAnyConv):
             w.shape[1], h_src.dtype, self.activation_name,
             n_edges=int(es.adjacency.source.shape[0]),
             sorted_ids=self._sorted_hint())
+
+    def describe_dispatch(self, params, graph: GraphTensor,
+                          edge_set_name: str) -> kernel_dispatch.Decision:
+        """The dispatch decision a call on `graph` takes: the fused
+        `edge_mpnn` one when eligible, else the generic path's pool into
+        a node set, whose reason also says why fusion was off."""
+        fused = self.fused_decision(params, graph, edge_set_name)
+        if fused.use_kernel or self.receiver_tag == CONTEXT:
+            return fused
+        es = graph.edge_sets[edge_set_name]
+        sender_name, recv_name = self._fused_endpoints(es)
+        dtypes = [graph.node_sets[sender_name][self.sender_node_feature]
+                  .dtype] if self.sender_node_feature else []
+        if self.sender_edge_feature:
+            dtypes.append(es[self.sender_edge_feature].dtype)
+        if self.combine_receiver and self.receiver_feature:
+            dtypes.append(
+                graph.node_sets[recv_name][self.receiver_feature].dtype)
+        units = params["message"]["w"].shape[1]
+        pool = kernel_dispatch.segment_reduce_decision(
+            (int(es.adjacency.source.shape[0]), units),
+            jnp.result_type(*dtypes), graph.node_sets[recv_name].capacity,
+            self.reduce_type, sorted_ids=self._sorted_hint())
+        return dataclasses.replace(
+            pool, reason=f"unfused ({fused.reason}); pool {pool.reason}")
 
     def _fused_endpoints(self, es):
         if self.receiver_tag == TARGET:

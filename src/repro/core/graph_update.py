@@ -78,10 +78,11 @@ class NodeSetUpdate(Module):
         self.next_state = next_state
 
     def describe_dispatch(self, params, graph: GraphTensor) -> dict:
-        """{edge_set_name: dispatch Decision (or None for generic convs)}."""
-        return {name: (conv.fused_decision(params["convs"][name], graph,
-                                           name)
-                       if hasattr(conv, "fused_decision") else None)
+        """{edge_set_name: dispatch Decision (or None for convs that do
+        not report one)}: the fused kernel's, or else the pool's."""
+        return {name: (conv.describe_dispatch(params["convs"][name], graph,
+                                              name)
+                       if hasattr(conv, "describe_dispatch") else None)
                 for name, conv in self.convs.items()}
 
     def init(self, key):
@@ -191,7 +192,8 @@ class GraphUpdate(Module):
 
     def describe_dispatch(self, params, graph: GraphTensor) -> dict:
         """{node_set_name: {edge_set_name: dispatch Decision | None}} —
-        which kernel path each conv of this round would take on `graph`."""
+        which kernel path (fused conv or pool) each conv of this round
+        would take on `graph`, and why."""
         return {name: upd.describe_dispatch(params["node_sets"][name],
                                             graph)
                 for name, upd in self.node_sets.items()

@@ -46,6 +46,7 @@ from typing import Any, Callable, Mapping, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.mp_context import (ModelContext,  # noqa: F401 (API)
@@ -55,29 +56,10 @@ from repro.distributed.sharding import (DEFAULT_ACT_RULES,
                                         DEFAULT_PARAM_RULES, ShardingContext,
                                         data_axis_names, is_axes_leaf)
 
-try:  # jax >= 0.6 exports shard_map at the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 GROUP_AXIS = "batch"    # logical name of the leading component-group axis
 FEATURE_AXIS = "feature"  # logical name of a trailing feature axis
 MODEL_AXIS = "model"    # mesh axis carrying feature-dim model parallelism
-
-
-def _shard_map_norep(f, mesh, in_specs, out_specs):
-    """shard_map without the replication checker: our replicated outputs
-    are pmean/psum/all_gather results, so the proof adds tracing cost
-    without value.  The disabling kwarg was renamed across jax versions
-    (check_rep -> check_vma); fall back to defaults when neither exists."""
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise TypeError("shard_map rejected all known signatures")
 
 
 # ---------------------------------------------------------------------------
@@ -111,12 +93,9 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
               else os.environ.get("REPRO_PROCESS_ID", "0") or 0)
     if not coord or nproc <= 1:
         return False
-    try:
-        # CPU cross-process collectives (the CI / test backend) need the
-        # gloo implementation; harmless no-op where already configured
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — flag renamed/absent on this jax
-        pass
+    # CPU cross-process collectives (the CI / test backend) need the gloo
+    # implementation
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coord,
                                num_processes=nproc, process_id=pid)
     return True
@@ -641,10 +620,12 @@ def make_train_step(plan: MeshPlan, loss_fn: Callable, optimizer, *,
                                                         params)
             return params, opt_local, loss
 
-        sharded = _shard_map_norep(
-            body, mesh,
+        # no replication checker: the replicated outputs are pmean/psum/
+        # all_gather results, so its proof adds tracing cost, not value
+        sharded = shard_map(
+            body, mesh=mesh,
             in_specs=(P(), sspecs, gspecs, plan.data_spec()),
-            out_specs=(P(), sspecs, P()))
+            out_specs=(P(), sspecs, P()), check_vma=False)
         return sharded(params, opt_state, graph_stack, labels)
 
     # donate params/opt_state: the returned trees reuse the input buffers,
@@ -677,10 +658,10 @@ def make_eval_step(plan: MeshPlan, metric_fn: Callable) -> Callable:
                         a + b for a, b in zip(totals, out))
             return tuple(jax.lax.psum(t, plan.data_axes) for t in totals)
 
-        sharded = _shard_map_norep(
-            body, mesh,
+        sharded = shard_map(
+            body, mesh=mesh,
             in_specs=(P(), gspecs, plan.data_spec()),
-            out_specs=P())
+            out_specs=P(), check_vma=False)
         return sharded(params, graph_stack, labels)
 
     return jax.jit(eval_step)

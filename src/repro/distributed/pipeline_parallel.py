@@ -17,8 +17,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(body: Callable, mesh: Mesh, *, stage_axis: str = "stage",
@@ -78,4 +78,4 @@ def pipeline_apply(body: Callable, mesh: Mesh, *, stage_axis: str = "stage",
     in_specs = (P(stage_axis), P())
     out_specs = P()
     return shard_map(pipelined, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)

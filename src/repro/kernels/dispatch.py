@@ -305,19 +305,23 @@ def choose_mpnn_e_block(n_src: int, n_tgt: int, ds: int, dt: int, m: int,
 
     The CSR-run variant gathers with per-row dynamic loads and pools with
     a run scan, so its per-edge cost drops the n_src/n_tgt one-hot terms.
+    It holds node states and gathered rows in fp32 whatever the input
+    dtype (single-row dynamic access needs unpacked rows).
     """
+    if variant == "runs":
+        resident = (n_src * ds + n_tgt * dt) * 4 \
+            + (ds + dt) * m * itemsize + n_tgt * m * 4  # fp32 accumulator
+        per_edge = ((ds + dt) * (4 + itemsize)  # fp32 gathered rows + concat
+                    + 2 * m * 4                 # fp32 message + scan temp
+                    + 16)                       # edge ids
+        return _fit_block(resident, per_edge, n_edges)
     resident = (n_src * ds + n_tgt * dt + (ds + dt) * m) * itemsize \
         + n_tgt * m * 4  # fp32 accumulator
-    if variant == "runs":
-        per_edge = (2 * (ds + dt) * itemsize  # gathered-row scratch + concat
-                    + 2 * m * 4               # fp32 message + scan temp
-                    + 16)                     # edge ids
-    else:
-        per_edge = (n_src * itemsize            # src one-hot
-                    + n_tgt * (itemsize + 4)    # tgt one-hot (+ fp32 copy)
-                    + 2 * (ds + dt) * itemsize  # gathered states + concat
-                    + m * 4                     # fp32 message row
-                    + 8)                        # edge ids
+    per_edge = (n_src * itemsize            # src one-hot
+                + n_tgt * (itemsize + 4)    # tgt one-hot (+ fp32 copy)
+                + 2 * (ds + dt) * itemsize  # gathered states + concat
+                + m * 4                     # fp32 message row
+                + 8)                        # edge ids
     return _fit_block(resident, per_edge, n_edges)
 
 

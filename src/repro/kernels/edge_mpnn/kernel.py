@@ -53,10 +53,14 @@ def _edge_mpnn_kernel(h_src_ref, h_tgt_ref, src_ref, tgt_ref, w_ref, b_ref,
         jnp.int32, (e_block, n_src), 1)).astype(h_src_ref.dtype)
     oh_tgt = (tgt == jax.lax.broadcasted_iota(
         jnp.int32, (e_block, n_tgt), 1)).astype(h_tgt_ref.dtype)
-    hs = jax.lax.dot_general(oh_src, h_src_ref[...],
-                             (((1,), (0,)), ((), ())))  # [E_blk, Ds]
-    ht = jax.lax.dot_general(oh_tgt, h_tgt_ref[...],
-                             (((1,), (0,)), ((), ())))  # [E_blk, Dt]
+    # fp32 accumulation (Mosaic requires it); a one-hot row selects one
+    # value exactly, so the cast back to the state dtype is lossless
+    hs = jax.lax.dot_general(
+        oh_src, h_src_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(h_src_ref.dtype)
+    ht = jax.lax.dot_general(
+        oh_tgt, h_tgt_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(h_tgt_ref.dtype)
     x = jnp.concatenate([hs, ht], axis=-1)
     # message transform in fp32: bf16 inputs round once here, not per-op
     msg = jax.lax.dot_general(x, w_ref[...], (((1,), (0,)), ((), ())),
@@ -74,8 +78,9 @@ def _edge_mpnn_kernel(h_src_ref, h_tgt_ref, src_ref, tgt_ref, w_ref, b_ref,
 
 
 def _edge_mpnn_runs_kernel(h_src_ref, h_tgt_ref, src_ref, tgt_ref, w_ref,
-                           b_ref, out_ref, x_scr, m_scr, *, e_block: int,
-                           n_src: int, n_tgt: int, activation: str):
+                           b_ref, out_ref, hs_scr, ht_scr, m_scr, *,
+                           e_block: int, n_src: int, n_tgt: int,
+                           activation: str, x_dtype):
     from repro.kernels.segment_pool.kernel import segmented_run_scan
     step = pl.program_id(0)
 
@@ -83,19 +88,23 @@ def _edge_mpnn_runs_kernel(h_src_ref, h_tgt_ref, src_ref, tgt_ref, w_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    ds = h_src_ref.shape[1]
     # gather via per-row dynamic loads into scratch — O(Ds+Dt) per edge
-    # instead of the O(n_src + n_tgt) one-hots
+    # instead of the O(n_src + n_tgt) one-hots.  States arrive as fp32
+    # (see edge_mpnn_runs) and each side has its own full-width scratch:
+    # Mosaic refuses single-row dynamic loads and stores on packed 16-bit
+    # rows, and a dynamic row store into a lane slice of a wider buffer.
     def _gather(i, carry):
         s = jnp.minimum(src_ref[i, 0], n_src - 1)
         t = jnp.minimum(tgt_ref[i, 0], n_tgt - 1)  # clamp padding rows
-        x_scr[pl.ds(i, 1), :ds] = h_src_ref[pl.ds(s, 1), :]
-        x_scr[pl.ds(i, 1), ds:] = h_tgt_ref[pl.ds(t, 1), :]
+        hs_scr[pl.ds(i, 1), :] = h_src_ref[pl.ds(s, 1), :]
+        ht_scr[pl.ds(i, 1), :] = h_tgt_ref[pl.ds(t, 1), :]
         return carry
 
     jax.lax.fori_loop(0, e_block, _gather, 0)
+    # back to the caller's state dtype (exact: the values came from it)
+    x = jnp.concatenate([hs_scr[...], ht_scr[...]], axis=-1).astype(x_dtype)
     # message transform in fp32: bf16 inputs round once here, not per-op
-    msg = jax.lax.dot_general(x_scr[...], w_ref[...],
+    msg = jax.lax.dot_general(x, w_ref[...],
                               (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
     msg = msg + b_ref[...].astype(jnp.float32)
@@ -141,6 +150,7 @@ def edge_mpnn_runs(h_src: jnp.ndarray, h_tgt: jnp.ndarray, src: jnp.ndarray,
     e = src.shape[0]
     m = w.shape[1]
     ds, dt = h_src.shape[1], h_tgt.shape[1]
+    x_dtype = h_src.dtype
     if e_block is None:
         from repro.kernels import dispatch as _dispatch
         e_block = _dispatch.choose_mpnn_e_block(
@@ -157,7 +167,8 @@ def edge_mpnn_runs(h_src: jnp.ndarray, h_tgt: jnp.ndarray, src: jnp.ndarray,
     e_tot = src.shape[0]
     out = pl.pallas_call(
         functools.partial(_edge_mpnn_runs_kernel, e_block=e_block,
-                          n_src=n_src, n_tgt=n_tgt, activation=activation),
+                          n_src=n_src, n_tgt=n_tgt, activation=activation,
+                          x_dtype=x_dtype),
         grid=(e_tot // e_block,),
         in_specs=[
             pl.BlockSpec((n_src, ds), lambda i: (0, 0)),
@@ -169,12 +180,14 @@ def edge_mpnn_runs(h_src: jnp.ndarray, h_tgt: jnp.ndarray, src: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((n_tgt, m), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tgt, m), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((e_block, ds + dt), h_src.dtype),
+        scratch_shapes=[pltpu.VMEM((e_block, ds), jnp.float32),
+                        pltpu.VMEM((e_block, dt), jnp.float32),
                         pltpu.VMEM((e_block, m), jnp.float32)],
         interpret=interpret,
-    )(h_src, h_tgt, src.astype(jnp.int32).reshape(-1, 1),
+    )(h_src.astype(jnp.float32), h_tgt.astype(jnp.float32),
+      src.astype(jnp.int32).reshape(-1, 1),
       tgt.astype(jnp.int32).reshape(-1, 1), w, b.reshape(1, -1))
-    return out.astype(h_src.dtype)
+    return out.astype(x_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n_src", "n_tgt", "e_block",

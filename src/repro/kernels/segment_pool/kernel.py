@@ -71,10 +71,11 @@ def _seg_max_kernel(values_ref, segs_ref, out_ref, *, n_segments: int,
 
     vals = values_ref[...].astype(jnp.float32)
     segs = segs_ref[...]
-    mask = segs == jax.lax.broadcasted_iota(
-        jnp.int32, (e_block, n_segments), 1)  # [E_blk, N]
-    # [E_blk, N, D] masked broadcast, reduced over the edge dim
-    contrib = jnp.where(mask[:, :, None], vals[:, None, :], NEG_INF)
+    # [E_blk, N, D] masked broadcast, reduced over the edge dim.  The mask
+    # is compared at rank 3 directly: Mosaic cannot reshape an i1 vector.
+    ids = jax.lax.broadcasted_iota(
+        jnp.int32, (e_block, n_segments, vals.shape[1]), 1)
+    contrib = jnp.where(segs[:, :, None] == ids, vals[:, None, :], NEG_INF)
     out_ref[...] = jnp.maximum(out_ref[...], contrib.max(axis=0))
 
 
@@ -85,19 +86,22 @@ def segmented_run_scan(x: jnp.ndarray, segs: jnp.ndarray, e_block: int,
     marks run heads and is OR-propagated so a combine never reaches
     across a run boundary, which keeps unsorted ids correct (two runs
     of the same segment fold independently and meet in the accumulator).
-    x: [E_blk, D]; segs: [E_blk, 1] int32.  Shared with edge_mpnn_runs."""
-    prev = jnp.concatenate(
-        [jnp.full((1, 1), -1, jnp.int32), segs[:-1]], axis=0)
-    flag = segs != prev
+    x: [E_blk, D]; segs: [E_blk, 1] int32.  Shared with edge_mpnn_runs.
+
+    Shifts are sublane rotations (`pltpu.roll`) with the wrapped rows
+    masked off, and flags are int32 0/1: Mosaic can neither cast i1
+    vectors to i32 nor concatenate at unaligned sublane offsets."""
+    row = jax.lax.broadcasted_iota(jnp.int32, segs.shape, 0)
+    x_row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    prev = jnp.where(row == 0, -1, pltpu.roll(segs, 1, 0))
+    flag = jnp.where(segs != prev, 1, 0)
     dist = 1
     while dist < e_block:
-        x_sh = jnp.concatenate(
-            [jnp.full((dist, x.shape[1]), identity, x.dtype), x[:-dist]],
-            axis=0)
-        f_sh = jnp.concatenate(
-            [jnp.ones((dist, 1), jnp.bool_), flag[:-dist]], axis=0)
-        x = jnp.where(flag, x, combine(x_sh, x))
-        flag = jnp.logical_or(flag, f_sh)
+        x_sh = jnp.where(x_row < dist, identity, pltpu.roll(x, dist, 0))
+        f_sh = jnp.where(row < dist, 1, pltpu.roll(flag, dist, 0))
+        x = jnp.where(jnp.broadcast_to(flag, x.shape) > 0, x,
+                      combine(x_sh, x))
+        flag = jnp.maximum(flag, f_sh)
         dist *= 2
     return x
 

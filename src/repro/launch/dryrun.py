@@ -25,7 +25,6 @@ from pathlib import Path
 import jax
 
 from repro.distributed.sharding import param_shardings, use_sharding
-from repro.launch.roofline import cost_analysis_dict
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import make_cell
 from repro.models.registry import runnable_cells
@@ -112,7 +111,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool,
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
             mem = compiled.memory_analysis()
-            cost = cost_analysis_dict(compiled)
+            cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = parse_collectives(hlo)
     result = {

@@ -21,10 +21,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"need {n} devices, have {len(devices)} — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             f"(launch/dryrun.py sets this)")
-    try:
-        return jax.make_mesh(shape, axes, devices=devices[:n])
-    except TypeError:  # older jax without devices kwarg
-        return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_host_mesh(n_devices: int | None = None,

@@ -30,27 +30,6 @@ ICI_BW = 50e9                # B/s / link
 ICI_LINKS = 4                # v5e 2D torus: 4 links/chip
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """Normalised `compiled.cost_analysis()` across JAX versions.
-
-    Older JAX returns a single {metric: value} dict; newer JAX returns a
-    list with one such dict per device/computation.  Always returns one
-    merged dict (values summed across list entries, which is the whole-job
-    count the roofline math wants).
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, dict):
-        return dict(cost)
-    merged: dict = {}
-    for entry in cost or []:
-        for key, value in entry.items():
-            try:
-                merged[key] = merged.get(key, 0.0) + float(value)
-            except (TypeError, ValueError):
-                merged.setdefault(key, value)
-    return merged
-
-
 # ---------------------------------------------------------------------------
 # Analytic FLOPs / bytes per step (whole job, later divided by chips)
 # ---------------------------------------------------------------------------

@@ -131,7 +131,8 @@ class Trainer:
     def fit(self, model_fn: Callable, task, train_provider, *,
             eval_provider=None) -> RunResult:
         """Train `task` over `train_provider`; returns the final step,
-        last train loss, and a metrics dict with "params" (+ "eval",
+        last train loss, and a metrics dict with "params" and
+        "train_losses" (every step this call ran, in order; + "eval",
         "eval_history", "best_step" when an eval stream ran)."""
         init_states, gnn = model_fn()
         head = task.head()
@@ -219,6 +220,7 @@ class Trainer:
             else None)
         stop_early = False
         eval_history = []
+        train_losses = []
         last_loss = float("nan")
         cur_epoch = start_epoch
         step_in_epoch = epoch_start_step
@@ -278,6 +280,7 @@ class Trainer:
                     step += 1
                     step_in_epoch += 1
                     last_loss = float(loss)
+                    train_losses.append(last_loss)
                     if step % self.log_every == 0 and is_main:
                         print(f"epoch {epoch} step {step} "
                               f"loss {last_loss:.4f} "
@@ -326,4 +329,5 @@ class Trainer:
         if stop_early:
             metrics["stopped_early"] = True
         metrics["params"] = params
+        metrics["train_losses"] = train_losses
         return RunResult(step, last_loss, metrics)

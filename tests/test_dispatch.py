@@ -159,6 +159,29 @@ def test_graph_update_round_parity_and_describe():
         rtol=1e-4, atol=1e-4)
 
 
+def test_describe_dispatch_reports_the_pool_of_an_unfused_conv():
+    """A conv the fused kernel cannot take reports the decision of the
+    pool its generic path runs, and says why it was not fused."""
+    from repro.core.convolutions import SimpleConv
+    from repro.nn.module import split_params
+    g = padded_graph()
+    conv = SimpleConv(16, 8 + 8, reduce_type="max", receiver_tag=TARGET,
+                      sender_node_feature="h", receiver_feature="h")
+    params, _ = split_params(conv.init(jax.random.PRNGKey(0)))
+    adj = g.edge_sets["purchased"].adjacency
+    assert not conv.describe_dispatch(params, g, "purchased").use_kernel
+    with kernels_on():
+        dec = conv.describe_dispatch(params, g, "purchased")
+        pool = dispatch.segment_reduce_decision(
+            (adj.source.shape[0], 16), jnp.float32,
+            g.node_sets[adj.target_name].capacity, "max")
+    assert dec.use_kernel and pool.use_kernel
+    assert (dec.variant, dec.e_block, dec.interpret) == \
+        (pool.variant, pool.e_block, pool.interpret)
+    assert "max pooling not fused" in dec.reason
+    assert pool.reason in dec.reason
+
+
 # ---------------------------------------------------------------------------
 # Eligibility rules
 # ---------------------------------------------------------------------------

@@ -150,9 +150,9 @@ def test_adamw_state_specs_zero_path():
 # ---------------------------------------------------------------------------
 
 def _shard_map_1dev(f, *args):
-    from repro.distributed.partition import _shard_map_norep
     mesh = partition.make_mesh(1)
-    return _shard_map_norep(f, mesh, in_specs=P(), out_specs=P())(*args)
+    return jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
 
 
 def test_global_norm_psum_correction_matches_plain():

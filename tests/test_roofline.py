@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ShapeConfig, smoke_config
-from repro.launch.roofline import cost_analysis_dict, step_flops
+from repro.launch.roofline import step_flops
 from repro.models.registry import build_model, get_config
 from repro.nn.module import split_params
 
@@ -27,8 +27,8 @@ def test_cost_analysis_counts_scan_body_once():
     x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     w8 = jax.ShapeDtypeStruct((8, 128, 128), jnp.float32)
     w1 = jax.ShapeDtypeStruct((1, 128, 128), jnp.float32)
-    f8 = cost_analysis_dict(jax.jit(scanned).lower(x, w8).compile())["flops"]
-    f1 = cost_analysis_dict(jax.jit(scanned).lower(x, w1).compile())["flops"]
+    f8 = jax.jit(scanned).lower(x, w8).compile().cost_analysis()["flops"]
+    f1 = jax.jit(scanned).lower(x, w1).compile().cost_analysis()["flops"]
     assert f8 == pytest.approx(f1, rel=0.01), \
         "cost_analysis no longer undercounts scans — roofline can switch " \
         "to HLO FLOPs directly"
@@ -44,8 +44,8 @@ def test_analytic_flops_match_cost_analysis_per_layer():
     toks = jax.ShapeDtypeStruct((b, s), jnp.int32)
     pspec = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
-    cost = cost_analysis_dict(jax.jit(lambda p, t: model(p, t).logits)
-                              .lower(pspec, toks).compile())
+    cost = (jax.jit(lambda p, t: model(p, t).logits)
+            .lower(pspec, toks).compile().cost_analysis())
     hlo_flops = cost["flops"]
     shape = ShapeConfig("t", s, b, "prefill")  # fwd-only
     analytic = step_flops(cfg, shape)["compiled_flops"]

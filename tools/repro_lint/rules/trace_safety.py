@@ -54,7 +54,7 @@ def _is_tracing_callee(node: ast.AST, imports: dict[str, str]) -> bool:
     if text in _TRACING_ENTRY_POINTS:
         return True
     leaf = text.rsplit(".", 1)[-1]
-    # local wrappers like _shard_map_norep(body, mesh, ...) still trace
+    # local wrappers named like *shard_map*(body, mesh, ...) still trace
     # their function argument
     return "shard_map" in leaf or leaf == "pallas_call"
 
