@@ -159,7 +159,8 @@ class GraphUpdate(Module):
 
     Applies (in order): edge-set updates, node-set updates, context update —
     the Graph Networks schedule generalised to named sets.  Each returns a
-    new GraphTensor with replaced hidden states.
+    new GraphTensor with replaced hidden states; an edge- or node-set
+    update runs under a `jax.named_scope` named for its set.
 
     With kernels enabled (repro.core.ops.use_kernels / REPRO_KERNELS) the
     hot path of a round — gather, per-edge message, scatter-pool — runs
@@ -204,16 +205,18 @@ class GraphUpdate(Module):
             new_edge_feats = {}
             for name, upd in self.edge_sets.items():
                 feats = dict(graph.edge_sets[name].features)
-                feats[HIDDEN_STATE] = upd(params["edge_sets"][name], graph,
-                                          name)
+                with jax.named_scope(name):
+                    feats[HIDDEN_STATE] = upd(params["edge_sets"][name],
+                                              graph, name)
                 new_edge_feats[name] = feats
             graph = graph.replace_features(edge_sets=new_edge_feats)
         if self.node_sets:
             new_node_feats = {}
             for name, upd in self.node_sets.items():
                 feats = dict(graph.node_sets[name].features)
-                feats[HIDDEN_STATE] = upd(params["node_sets"][name], graph,
-                                          name)
+                with jax.named_scope(name):
+                    feats[HIDDEN_STATE] = upd(params["node_sets"][name],
+                                              graph, name)
                 new_node_feats[name] = feats
             graph = graph.replace_features(node_sets=new_node_feats)
         if self.context is not None:

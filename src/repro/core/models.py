@@ -33,7 +33,8 @@ def incident_edge_sets(schema_edges: Mapping[str, tuple[str, str]],
 
 
 class GNNStack(Module):
-    """A sequence of GraphUpdate rounds (optionally weight-shared)."""
+    """A sequence of GraphUpdate rounds (optionally weight-shared), each
+    under a `jax.named_scope` ``round_<i>``."""
 
     def __init__(self, updates: Sequence[GraphUpdate], *,
                  share_weights: bool = False):
@@ -47,8 +48,9 @@ class GNNStack(Module):
         return {"rounds": [u.init(k) for u, k in zip(self.updates, keys)]}
 
     def __call__(self, params, graph: GraphTensor) -> GraphTensor:
-        for upd, p in zip(self.updates, params["rounds"]):
-            graph = upd(p, graph)
+        for i, (upd, p) in enumerate(zip(self.updates, params["rounds"])):
+            with jax.named_scope(f"round_{i}"):
+                graph = upd(p, graph)
         return graph
 
 

@@ -17,6 +17,7 @@ yield 0).
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -28,6 +29,18 @@ from repro.core.graph_tensor import (CONTEXT, GraphTensor, HIDDEN_STATE,
 from repro.kernels import dispatch as kernel_dispatch
 
 _REDUCE_TYPES = ("sum", "mean", "max", "min")
+
+
+def _scoped(name: str):
+    """Runs the op under `jax.named_scope(name)`, so a trace of the
+    compiled program can tell ``pool`` and ``broadcast`` time apart."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -96,6 +109,7 @@ def _resolve_feature(piece, feature_name, feature_value):
 # node <-> edge
 # ---------------------------------------------------------------------------
 
+@_scoped("broadcast")
 def broadcast_node_to_edges(graph: GraphTensor, edge_set_name: str, tag: str,
                             *, feature_name: str | None = None,
                             feature_value=None):
@@ -106,6 +120,7 @@ def broadcast_node_to_edges(graph: GraphTensor, edge_set_name: str, tag: str,
     return jnp.take(value, idx, axis=0)
 
 
+@_scoped("pool")
 def pool_edges_to_node(graph: GraphTensor, edge_set_name: str, tag: str,
                        reduce_type: str = "sum", *,
                        feature_name: str | None = None, feature_value=None):
@@ -128,6 +143,7 @@ def pool_edges_to_node(graph: GraphTensor, edge_set_name: str, tag: str,
                               sorted_ids=None if tag == TARGET else False)
 
 
+@_scoped("pool")
 def segment_softmax(graph: GraphTensor, edge_set_name: str, tag: str,
                     *, feature_value):
     """Softmax of per-edge scores within each receiver node's edge segment
@@ -162,6 +178,7 @@ def _piece(graph: GraphTensor, name: str, node_or_edge: str):
             else graph.edge_sets[name])
 
 
+@_scoped("broadcast")
 def broadcast_context_to_nodes(graph: GraphTensor, node_set_name: str, *,
                                feature_name: str | None = None,
                                feature_value=None):
@@ -170,6 +187,7 @@ def broadcast_context_to_nodes(graph: GraphTensor, node_set_name: str, *,
     return jnp.take(value, jnp.minimum(comp, value.shape[0] - 1), axis=0)
 
 
+@_scoped("broadcast")
 def broadcast_context_to_edges(graph: GraphTensor, edge_set_name: str, *,
                                feature_name: str | None = None,
                                feature_value=None):
@@ -178,6 +196,7 @@ def broadcast_context_to_edges(graph: GraphTensor, edge_set_name: str, *,
     return jnp.take(value, jnp.minimum(comp, value.shape[0] - 1), axis=0)
 
 
+@_scoped("pool")
 def _pool_items_to_context(piece, num_components, reduce_type, value):
     if reduce_type not in _REDUCE_TYPES:
         raise ValueError(f"unknown reduce_type {reduce_type!r}")
@@ -211,6 +230,7 @@ def pool_edges_to_context(graph: GraphTensor, edge_set_name: str,
                                   value)
 
 
+@_scoped("pool")
 def node_degree(graph: GraphTensor, edge_set_name: str, tag: str):
     """Valid-edge degree of each node at endpoint `tag`."""
     es = graph.edge_sets[edge_set_name]

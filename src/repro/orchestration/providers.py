@@ -34,6 +34,7 @@ from __future__ import annotations
 import itertools
 from typing import Callable, Iterator, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.core.graph_tensor import GraphTensor
@@ -69,7 +70,10 @@ class DatasetProvider:
 
 class BatcherProvider(DatasetProvider):
     """Pre-sampled in-memory graphs behind the contract (wraps
-    `GraphBatcher` — same constructor surface)."""
+    `GraphBatcher` — same constructor surface).  Under the JAX profiler
+    each step's merge/pad is a ``repro.merge_pad`` span with ``epoch``
+    and ``step`` stats; the spans live here, not in the numpy-only data
+    layer that forked sampler workers run."""
 
     def __init__(self, graphs: Sequence[GraphTensor], batch_size: int,
                  sizes: SizeConstraints, *, seed: int = 0, rank: int = 0,
@@ -86,7 +90,14 @@ class BatcherProvider(DatasetProvider):
         return self.batcher.num_steps
 
     def epoch(self, epoch: int, *, start_step: int = 0) -> Iterator:
-        return self.batcher.epoch(epoch, start_step=start_step)
+        batches = self.batcher.epoch(epoch, start_step=start_step)
+        for step in itertools.count(start_step):
+            with jax.profiler.TraceAnnotation("repro.merge_pad",
+                                              epoch=epoch, step=step):
+                batch = next(batches, None)
+            if batch is None:
+                return
+            yield batch
 
 
 class ServiceProvider(DatasetProvider):
@@ -135,7 +146,9 @@ class StoreProvider(DatasetProvider):
     stream is bit-identical to a `BatcherProvider` over
     ``InMemorySampler(store, spec, seed=base_seed).sample(roots)`` with
     the same plan — while holding at most one step's subgraphs in
-    memory."""
+    memory.  Under the JAX profiler a step's sampling is a
+    ``repro.sample`` span and its merge/pad a ``repro.merge_pad`` span,
+    both with ``epoch`` and ``step`` stats."""
 
     def __init__(self, store: GraphStore, spec: SamplingSpec,
                  roots: Sequence[int], *, batch_size: int,
@@ -161,10 +174,15 @@ class StoreProvider(DatasetProvider):
         sizes = step_size_constraints(self.plan, self.sizes)
         for step in range(start_step, self.num_steps):
             idx = self.plan.step_indices(order, step)
-            graphs = [sample_subgraph(self.store, self.spec, int(r),
-                                      seed_rng(self.base_seed, int(r)))
-                      for r in (self.roots[i] for i in idx)]
-            yield build_batch(graphs, self.plan, sizes)
+            with jax.profiler.TraceAnnotation("repro.sample", epoch=epoch,
+                                              step=step):
+                graphs = [sample_subgraph(self.store, self.spec, int(r),
+                                          seed_rng(self.base_seed, int(r)))
+                          for r in (self.roots[i] for i in idx)]
+            with jax.profiler.TraceAnnotation("repro.merge_pad",
+                                              epoch=epoch, step=step):
+                batch = build_batch(graphs, self.plan, sizes)
+            yield batch
 
 
 class IteratorProvider(DatasetProvider):

@@ -37,6 +37,7 @@ from repro.distributed.fault_tolerance import CheckpointManager
 from repro.kernels import dispatch as kernel_dispatch
 from repro.nn.module import split_params
 from repro.orchestration.evaluation import EarlyStopping, evaluate
+from repro.runtime import compile_count
 from repro.train.optimizer import AdamW, warmup_cosine
 from repro.train.train_loop import (device_prefetch, make_graph_eval_step,
                                     make_graph_train_step)
@@ -119,21 +120,34 @@ class Trainer:
 
     @staticmethod
     def _labeled(stream, task, epoch: int, start_step: int):
-        """Normalize a provider stream to (graph, labels) pairs: sources
+        """Normalize a provider stream to (step, graph, labels): sources
         that pre-compute labels pass through; bare graphs go through the
         Task's extraction at the stream's (epoch, step) coordinates."""
         for step, item in enumerate(stream, start=start_step):
             if isinstance(item, tuple):
-                yield item
+                yield (step, *item)
             else:
-                yield item, task.labels(item, epoch=epoch, step=step)
+                with jax.profiler.TraceAnnotation("repro.labels",
+                                                  epoch=epoch, step=step):
+                    labels = task.labels(item, epoch=epoch, step=step)
+                yield step, item, labels
 
     def fit(self, model_fn: Callable, task, train_provider, *,
             eval_provider=None) -> RunResult:
         """Train `task` over `train_provider`; returns the final step,
-        last train loss, and a metrics dict with "params" and
-        "train_losses" (every step this call ran, in order; + "eval",
-        "eval_history", "best_step" when an eval stream ran)."""
+        last train loss, and a metrics dict with "params",
+        "train_losses" (every step this call ran, in order) and
+        "step_compiles" (the train step's programs compiled so far,
+        after each of those steps; None where the step is not a jitted
+        function); + "eval", "eval_history", "best_step" when an eval
+        stream ran.
+
+        Under the JAX profiler each step leaves the host spans
+        ``repro.labels`` (`Task.labels`), ``repro.place``,
+        ``repro.dispatch`` (the jitted step call) and ``repro.readback``
+        (``float(loss)``, where the host waits for the chip), each with
+        the stream's ``epoch`` and ``step`` as stats, beside the
+        provider's own ``repro.sample``/``repro.merge_pad``."""
         init_states, gnn = model_fn()
         head = task.head()
         params = self._init_params(init_states, gnn, head)
@@ -144,9 +158,13 @@ class Trainer:
         opt_state = opt.init(params)
 
         def loss_fn(params, graph, labels):
-            graph_out = gnn(params["gnn"], init_states(params["init"],
-                                                       graph))
-            return task.loss_from_graph(params["head"], graph_out, labels)
+            with jax.named_scope("init_states"):
+                graph = init_states(params["init"], graph)
+            with jax.named_scope("gnn"):
+                graph_out = gnn(params["gnn"], graph)
+            with jax.named_scope("head"):
+                return task.loss_from_graph(params["head"], graph_out,
+                                            labels)
 
         metric_keys = tuple(task.metric_names())
 
@@ -221,6 +239,7 @@ class Trainer:
         stop_early = False
         eval_history = []
         train_losses = []
+        step_compiles = []
         last_loss = float("nan")
         cur_epoch = start_epoch
         step_in_epoch = epoch_start_step
@@ -252,35 +271,46 @@ class Trainer:
                 pairs = self._labeled(
                     train_provider.epoch(epoch, start_step=start),
                     task, epoch, start)
+
+                def place_step(k, graph, labels, epoch=epoch):
+                    with jax.profiler.TraceAnnotation(
+                            "repro.place", epoch=epoch, step=k):
+                        return place(graph, labels)
+
                 if self.double_buffer:
-                    placed = device_prefetch(pairs, place)
+                    placed = device_prefetch(pairs, place_step)
                 else:
-                    placed = (place(g, l) for g, l in pairs)
+                    placed = (place_step(*p) for p in pairs)
                 step_in_epoch = start
                 for graph, labels in placed:
                     if self.max_steps is not None \
                             and step >= self.max_steps:
                         placed.close()  # joins the device_prefetch thread
                         break
-                    if plan is not None:
-                        if dp_train_step is None:
-                            from repro.core.graph_tensor import stack_size
-                            dp_train_step = make_graph_train_step(
-                                loss_fn, opt, plan=plan,
-                                num_groups=stack_size(graph))
-                            params = plan.replicate(params)
-                            # ZeRO-1: AdamW m/v land "data"-sharded
-                            opt_state = plan.place_opt_state(opt, params,
-                                                             opt_state)
-                        params, opt_state, loss = dp_train_step(
+                    if plan is not None and dp_train_step is None:
+                        from repro.core.graph_tensor import stack_size
+                        dp_train_step = make_graph_train_step(
+                            loss_fn, opt, plan=plan,
+                            num_groups=stack_size(graph))
+                        params = plan.replicate(params)
+                        # ZeRO-1: AdamW m/v land "data"-sharded
+                        opt_state = plan.place_opt_state(opt, params,
+                                                         opt_state)
+                    train_step = (dp_train_step if plan is not None
+                                  else single_train_step)
+                    with jax.profiler.TraceAnnotation(
+                            "repro.dispatch", epoch=epoch,
+                            step=step_in_epoch):
+                        params, opt_state, loss = train_step(
                             params, opt_state, graph, labels)
-                    else:
-                        params, opt_state, loss = single_train_step(
-                            params, opt_state, graph, labels)
+                    with jax.profiler.TraceAnnotation(
+                            "repro.readback", epoch=epoch,
+                            step=step_in_epoch):
+                        last_loss = float(loss)
                     step += 1
                     step_in_epoch += 1
-                    last_loss = float(loss)
                     train_losses.append(last_loss)
+                    step_compiles.append(compile_count(train_step))
                     if step % self.log_every == 0 and is_main:
                         print(f"epoch {epoch} step {step} "
                               f"loss {last_loss:.4f} "
@@ -330,4 +360,5 @@ class Trainer:
             metrics["stopped_early"] = True
         metrics["params"] = params
         metrics["train_losses"] = train_losses
+        metrics["step_compiles"] = step_compiles
         return RunResult(step, last_loss, metrics)

@@ -4,7 +4,8 @@
 examples, ``benchmarks/run.py``) one persistent XLA compilation cache, so
 a second process compiling the same program reads it back instead of
 compiling again.  `tpu_chips_attached` tells a launcher that must stay
-off JAX whether this host has TPU chips.
+off JAX whether this host has TPU chips.  `compile_count` reads how many
+programs a jitted function has compiled, for `Trainer` and `GNNServer`.
 
 Importing this module imports nothing from JAX.
 """
@@ -13,6 +14,7 @@ from __future__ import annotations
 import glob
 import os
 from pathlib import Path
+from typing import Optional
 
 # A fixed path inside the checkout: a cache whose directory moves
 # between runs is never found again.
@@ -38,6 +40,14 @@ def enable_compile_cache() -> str:
     import jax
     jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
     return str(CACHE_DIR)
+
+
+def compile_count(fn) -> Optional[int]:
+    """Programs `fn` has compiled so far: the size of its jit cache, or
+    None where `fn` is not a jitted function (or this JAX version does
+    not expose the cache)."""
+    cache_size = getattr(fn, "_cache_size", None)
+    return int(cache_size()) if callable(cache_size) else None
 
 
 def tpu_chips_attached(pci_devices: str = "/sys/bus/pci/devices") -> int:

@@ -43,6 +43,7 @@ import numpy as np
 from repro.data.batching import SizeConstraints
 from repro.data.grouping import merge_and_pad
 from repro.data.sampling import GraphStore, SamplingSpec
+from repro.runtime import compile_count
 from repro.serve.cache import (MISSING, SubgraphCache, VersionedLRUCache)
 
 
@@ -304,10 +305,6 @@ class GNNServer:
 
     # -- compile accounting --------------------------------------------------
 
-    def _compile_count(self) -> Optional[int]:
-        cache_size = getattr(self._apply, "_cache_size", None)
-        return int(cache_size()) if callable(cache_size) else None
-
     @property
     def steady_state_recompiles(self) -> int:
         """Compilations after warmup.  Zero is the serving invariant:
@@ -315,7 +312,7 @@ class GNNServer:
         `warmup()`.  Uses the jit compilation-cache counter when the jax
         version exposes it, else falls back to bucket accounting (a
         bucket served that warmup never compiled implies a compile)."""
-        count = self._compile_count()
+        count = compile_count(self._apply)
         if count is not None:
             return count - self._warm_compiles
         return len(self._served_buckets - self._warm_buckets)
@@ -328,7 +325,7 @@ class GNNServer:
             merged = merge_and_pad([graph], self.ladder.sizes[rung])
             np.asarray(self._apply(self.params, merged))
             self._warm_buckets.add(rung)
-        count = self._compile_count()
+        count = compile_count(self._apply)
         self._warm_compiles = count if count is not None else 0
 
     # -- request admission ---------------------------------------------------

@@ -135,15 +135,20 @@ class AdamW:
                           jax.tree_util.tree_map(zeros, params),
                           jax.tree_util.tree_map(zeros, params))
 
+    @jax.named_scope("optimizer")
     def update(self, grads: PyTree, state: AdamWState, params: PyTree, *,
                axis_name=None, shard_dims: PyTree | None = None
                ) -> tuple[PyTree, AdamWState, dict]:
         """ZeRO-1: with ``axis_name``/``shard_dims`` the inputs are this
         data shard's slices; AdamW's update is elementwise, so only the
-        clipping norm needs the cross-shard psum correction."""
-        grads, gnorm = clip_by_global_norm(grads, self.max_grad_norm,
-                                           axis_name=axis_name,
-                                           shard_dims=shard_dims)
+        clipping norm needs the cross-shard psum correction.
+
+        Runs under `jax.named_scope` ``optimizer``, with ``clip`` around
+        the global-norm clip and ``update`` around the elementwise step."""
+        with jax.named_scope("clip"):
+            grads, gnorm = clip_by_global_norm(grads, self.max_grad_norm,
+                                               axis_name=axis_name,
+                                               shard_dims=shard_dims)
         step = state.step + 1
         b1, b2 = self.b1, self.b2
         bc1 = 1 - b1 ** step.astype(jnp.float32)
@@ -162,9 +167,10 @@ class AdamW:
             return (new_p.astype(p.dtype), m32.astype(self.moment_dtype),
                     v32.astype(self.moment_dtype))
 
-        out = jax.tree_util.tree_map(
-            lambda *ls: _maybe_chunked(upd, *ls),
-            params, grads, state.m, state.v)
+        with jax.named_scope("update"):
+            out = jax.tree_util.tree_map(
+                lambda *ls: _maybe_chunked(upd, *ls),
+                params, grads, state.m, state.v)
         new_params = jax.tree_util.tree_map(lambda o: o[0], out,
                                             is_leaf=lambda x: isinstance(x, tuple))
         new_m = jax.tree_util.tree_map(lambda o: o[1], out,
@@ -223,17 +229,21 @@ class Adafactor:
                               jax.tree_util.tree_map(vr, params),
                               jax.tree_util.tree_map(vc, params))
 
+    @jax.named_scope("optimizer")
     def update(self, grads, state, params, *, axis_name=None,
                shard_dims: PyTree | None = None):
         """ZeRO-1: with ``axis_name``/``shard_dims`` the inputs are this
         data shard's slices.  Unlike AdamW the factored statistics are
         not elementwise — any mean that reduces over a sliced dim (the
         column stats and rms normalizers of a row-sliced 2-D leaf) is
-        pmean-corrected so every shard reproduces the replicated math."""
+        pmean-corrected so every shard reproduces the replicated math.
+
+        Scopes as AdamW's: ``optimizer``, ``clip``, ``update``."""
         if self.max_grad_norm is not None:
-            grads, gnorm = clip_by_global_norm(grads, self.max_grad_norm,
-                                               axis_name=axis_name,
-                                               shard_dims=shard_dims)
+            with jax.named_scope("clip"):
+                grads, gnorm = clip_by_global_norm(
+                    grads, self.max_grad_norm, axis_name=axis_name,
+                    shard_dims=shard_dims)
         else:
             gnorm = jnp.zeros((), jnp.float32)
         step = state.step + 1
@@ -276,10 +286,12 @@ class Adafactor:
         # ZeRO slices skip chunking (they are 1/n_shards-sized already).
         dims = (shard_dims if shard_dims is not None
                 else jax.tree_util.tree_map(lambda p: -1, params))
-        out = jax.tree_util.tree_map(
-            lambda p, g, vr, vc, d: (upd(p, g, vr, vc, d) if d >= 0
-                                     else _maybe_chunked(upd, p, g, vr, vc)),
-            params, grads, state.vr, state.vc, dims)
+        with jax.named_scope("update"):
+            out = jax.tree_util.tree_map(
+                lambda p, g, vr, vc, d: (
+                    upd(p, g, vr, vc, d) if d >= 0
+                    else _maybe_chunked(upd, p, g, vr, vc)),
+                params, grads, state.vr, state.vc, dims)
         pick = lambda i: jax.tree_util.tree_map(
             lambda o: o[i], out, is_leaf=lambda x: isinstance(x, tuple))
         return (pick(0), AdafactorState(step, pick(1), pick(2)),

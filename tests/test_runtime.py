@@ -48,3 +48,14 @@ def test_tpu_chips_counts_google_tpu_devices_only(tmp_path):
     _pci(tmp_path, "0000:00:06.0", "0x1ae0", "0x0042")  # Google NIC
     _pci(tmp_path, "0000:00:07.0", "0x8086", "0x0063")  # other vendor
     assert runtime.tpu_chips_attached(str(tmp_path)) == 2
+
+
+def test_compile_count_reads_the_jit_cache():
+    f = jax.jit(lambda x: x + 1)
+    assert runtime.compile_count(f) == 0
+    f(jax.numpy.ones(3))
+    f(jax.numpy.zeros(3))
+    assert runtime.compile_count(f) == 1
+    f(jax.numpy.ones(4))
+    assert runtime.compile_count(f) == 2
+    assert runtime.compile_count(lambda x: x) is None
