@@ -9,6 +9,7 @@ sharding of m/v falls out of the same rule table.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -43,14 +44,37 @@ def constant_lr(lr: float):
 # Gradient clipping
 # ---------------------------------------------------------------------------
 
+def _f32_sqsum(x) -> jnp.ndarray:
+    return jnp.sum(jnp.square(x.astype(jnp.float32)))
+
+
 def _sqsum(x) -> jnp.ndarray:
     """Sum of squares in fp32 without materialising an fp32 copy of huge
-    leaves: chunk the reduction over the leading dim (the CPU pipeline does
-    not fuse convert+square into the reduce for multi-GiB tensors)."""
-    if x.size > 16 * 1024 * 1024 and x.ndim >= 2:
-        return jax.lax.map(
-            lambda s: jnp.sum(jnp.square(s.astype(jnp.float32))), x).sum()
-    return jnp.sum(jnp.square(x.astype(jnp.float32)))
+    leaves (the CPU pipeline does not fuse convert+square into the reduce
+    for multi-GiB tensors).
+
+    A leaf over ``SQSUM_BLOCK_ELEMS`` elements with ``ndim >= 2`` is reduced
+    in blocks of whole leading-dim rows, each block about
+    ``SQSUM_BLOCK_ELEMS`` elements, read in place by a ``fori_loop`` with the
+    remainder rows reduced after it.  Blocks follow the element count, not
+    the row count, because the leaves that cross the threshold come in both
+    shapes: a stacked-layer weight has a few dozen rows of millions of
+    elements (one row per block, the fp32 temp bounded by one row), while an
+    embedding table has a million rows of 128 (one row per iteration would
+    be a million-iteration loop of 512-byte reductions).  Smaller leaves
+    reduce whole."""
+    if x.size <= SQSUM_BLOCK_ELEMS or x.ndim < 2:
+        return _f32_sqsum(x)
+    rows = max(1, SQSUM_BLOCK_ELEMS // math.prod(x.shape[1:]))
+    n_full = x.shape[0] // rows
+    total = jax.lax.fori_loop(
+        0, n_full,
+        lambda i, acc: acc + _f32_sqsum(
+            jax.lax.dynamic_slice_in_dim(x, i * rows, rows)),
+        jnp.zeros((), jnp.float32))
+    if n_full * rows < x.shape[0]:
+        total = total + _f32_sqsum(x[n_full * rows:])
+    return total
 
 
 def global_norm(tree: PyTree, *, axis_name=None,
@@ -97,6 +121,10 @@ def clip_by_global_norm(tree: PyTree, max_norm: float, *, axis_name=None,
 # weight is 156B params; an fp32 temp of its per-device shard is 2.4 GiB —
 # times several temps times three such leaves without chunking).
 CHUNKED_UPDATE_THRESHOLD = 64 * 1024 * 1024
+
+# Leaves larger than this (elements) have their grad-norm sum of squares
+# reduced in blocks of about this many elements (`_sqsum`).
+SQSUM_BLOCK_ELEMS = 16 * 1024 * 1024
 
 
 def _maybe_chunked(fn, *leaves):
