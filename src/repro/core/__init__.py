@@ -40,7 +40,8 @@ _EXPORTS = {
     "GCNConv": "convolutions", "MultiHeadAttentionConv": "convolutions",
     "SAGEConv": "convolutions", "SimpleConv": "convolutions",
     "ContextUpdate": "graph_update", "EdgeSetUpdate": "graph_update",
-    "GraphUpdate": "graph_update", "MapFeatures": "graph_update",
+    "GraphUpdate": "graph_update", "HGTUpdate": "graph_update",
+    "MapFeatures": "graph_update",
     "NextStateFromConcat": "graph_update",
     "NodeSetUpdate": "graph_update", "ResidualNextState": "graph_update",
     "SingleInputNextState": "graph_update",

@@ -3,6 +3,7 @@ passing assembled from per-edge-set Convs and per-node-set NextState maps,
 plus optional edge-set and context updates (full Graph Networks)."""
 from __future__ import annotations
 
+import math
 from typing import Callable, Mapping, Optional
 
 import jax
@@ -11,8 +12,9 @@ import jax.numpy as jnp
 from repro.core import ops
 from repro.core.graph_tensor import (CONTEXT, GraphTensor, HIDDEN_STATE,
                                      SOURCE, TARGET)
-from repro.nn.layers import ACTIVATIONS, Linear, LayerNorm
-from repro.nn.module import Module
+from repro.nn.layers import (ACTIVATIONS, Linear, LayerNorm,
+                             rational_sigmoid)
+from repro.nn.module import Module, Param
 
 
 class NextStateFromConcat(Module):
@@ -224,6 +226,143 @@ class GraphUpdate(Module):
             feats[HIDDEN_STATE] = self.context(params["context"], graph)
             graph = graph.replace_features(context=feats)
         return graph
+
+
+class HGTUpdate(Module):
+    """One round of the Heterogeneous Graph Transformer (Hu, Dong, Wang,
+    Sun, WWW 2020, Eq. 1-5), over every node set that receives.
+
+    For an edge e = (s, t) of relation r, with t its receiving end by
+    `receiver_tag` and head i of width d:
+
+        ATT_i(e) = (K_i(s) W_att[r, i] . Q_i(t)) mu[r, i] / sqrt(d)
+        H^(t)    = concat_i sum_e softmax_i(e) V_i(s) W_msg[r, i]
+
+    where the softmax runs over every edge into t in all of its relations
+    at once (`ops.segment_softmax` over a list of edge sets), and
+
+        H'(t) = LayerNorm(sigmoid(alpha) A(GELU(H^(t)))
+                          + (1 - sigmoid(alpha)) H(t)).
+
+    K, V (sending sets), Q, A, alpha and the norm (receiving sets) are
+    keyed by node set, so every relation of a node set shares them;
+    W_att, W_msg and the prior mu are keyed by edge set.  The relation's
+    matrices run at node level: ``K W_att . Q = K . (W_att Q)`` at the
+    receiver, and ``sum_e c_e V_e W_msg = (sum_e c_e V_e) W_msg``, so no
+    per-edge matmul is needed.  A node set that receives over no edge
+    set keeps its state.  Each set's work runs under a
+    `jax.named_scope` of its name; the relation transforms and gathers
+    under ``hgt_relation``, the scores, joint softmax and weighted pool
+    under ``hgt_attention``.
+    """
+
+    def __init__(self, edges: Mapping[str, tuple[str, str]], dim: int, *,
+                 num_heads: int, receiver_tag: str = TARGET,
+                 use_layer_norm: bool = True):
+        if dim % num_heads:
+            raise ValueError(f"dim {dim} is not a multiple of num_heads "
+                             f"{num_heads}")
+        if receiver_tag not in (SOURCE, TARGET):
+            raise ValueError(f"receiver_tag must be SOURCE or TARGET, got "
+                             f"{receiver_tag!r}")
+        self.dim, self.num_heads = dim, num_heads
+        self.per_head = dim // num_heads
+        self.tag = receiver_tag
+        self.sender_tag = SOURCE if receiver_tag == TARGET else TARGET
+        # edge set -> (sending node set, receiving node set)
+        self.ends = {es: (src, tgt) if receiver_tag == TARGET else (tgt, src)
+                     for es, (src, tgt) in sorted(edges.items())}
+        self.incoming: dict = {}
+        for es, (_, recv) in self.ends.items():
+            self.incoming.setdefault(recv, []).append(es)
+        self.incoming = dict(sorted(self.incoming.items()))
+        self.senders = sorted({s for s, _ in self.ends.values()})
+        self.node_types = sorted(set(self.senders) | set(self.incoming))
+        self.proj = Linear(dim, dim)
+        self.norm = LayerNorm(dim) if use_layer_norm else None
+
+    def init(self, key):
+        p = {"node_sets": {}, "edge_sets": {}}
+        for ns in self.node_types:
+            key, *ks = jax.random.split(key, 5)
+            t = {}
+            if ns in self.senders:
+                t["k"], t["v"] = self.proj.init(ks[0]), self.proj.init(ks[1])
+            if ns in self.incoming:
+                t["q"], t["a"] = self.proj.init(ks[2]), self.proj.init(ks[3])
+                t["skip"] = {"scale": Param(jnp.ones((1,)), (None,))}
+                if self.norm is not None:
+                    t["norm"] = self.norm.init(None)
+            p["node_sets"][ns] = t
+        d, h = self.per_head, self.num_heads
+        for es in self.ends:
+            key, k1, k2 = jax.random.split(key, 3)
+            p["edge_sets"][es] = {
+                "att": {"w": Param(jax.random.normal(k1, (d, h, d))
+                                   / math.sqrt(d), (None, None, None))},
+                "msg": {"w": Param(jax.random.normal(k2, (d, h, d))
+                                   / math.sqrt(d), (None, None, None))},
+                "prior": {"scale": Param(jnp.ones((h,)), (None,))}}
+        return p
+
+    def _heads(self, x):
+        return x.reshape(x.shape[0], self.num_heads, self.per_head)
+
+    def __call__(self, params, graph: GraphTensor) -> GraphTensor:
+        keys, values, queries = {}, {}, {}
+        for ns in self.node_types:
+            p = params["node_sets"][ns]
+            h = graph.node_sets[ns][HIDDEN_STATE]
+            with jax.named_scope(ns):
+                if ns in self.senders:
+                    keys[ns] = self._heads(self.proj(p["k"], h))
+                    values[ns] = self._heads(self.proj(p["v"], h))
+                if ns in self.incoming:
+                    queries[ns] = self._heads(self.proj(p["q"], h))
+        new_feats = {}
+        for ns, incoming in self.incoming.items():
+            with jax.named_scope(ns):
+                feats = dict(graph.node_sets[ns].features)
+                feats[HIDDEN_STATE] = self._receive(
+                    params, graph, ns, incoming, keys, values, queries[ns])
+                new_feats[ns] = feats
+        return graph.replace_features(node_sets=new_feats)
+
+    def _receive(self, params, graph, ns, incoming, keys, values, query):
+        scores, sent = [], []
+        for es in incoming:
+            sender = self.ends[es][0]
+            r = params["edge_sets"][es]
+            with jax.named_scope("hgt_relation"):
+                q_att = jnp.einsum("nhf,dhf->nhd", query,
+                                   r["att"]["w"].astype(query.dtype))
+                k = ops.broadcast_node_to_edges(
+                    graph, es, self.sender_tag, feature_value=keys[sender])
+                q = ops.broadcast_node_to_edges(graph, es, self.tag,
+                                                feature_value=q_att)
+                sent.append(ops.broadcast_node_to_edges(
+                    graph, es, self.sender_tag,
+                    feature_value=values[sender]))
+            with jax.named_scope("hgt_attention"):
+                scores.append(jnp.sum(k * q, axis=-1) * r["prior"]["scale"]
+                              / math.sqrt(self.per_head))
+        with jax.named_scope("hgt_attention"):
+            coefs = ops.segment_softmax(graph, incoming, self.tag,
+                                        feature_value=scores)
+            pooled = [ops.pool_edges_to_node(graph, es, self.tag, "sum",
+                                             feature_value=v * c[..., None])
+                      for es, v, c in zip(incoming, sent, coefs)]
+        with jax.named_scope("hgt_relation"):
+            msg = sum(jnp.einsum("nhd,dhf->nhf", x, params["edge_sets"][es]
+                                 ["msg"]["w"].astype(x.dtype))
+                      for es, x in zip(incoming, pooled))
+        p = params["node_sets"][ns]
+        old = graph.node_sets[ns][HIDDEN_STATE]
+        update = self.proj(p["a"], jax.nn.gelu(
+            msg.reshape(msg.shape[0], self.dim), approximate=False))
+        alpha = rational_sigmoid(p["skip"]["scale"])
+        out = alpha * update + (1 - alpha) * old
+        return self.norm(p["norm"], out) if self.norm is not None else out
 
 
 class MapFeatures(Module):

@@ -18,10 +18,11 @@ from repro.core.convolutions import (GATv2Conv, GCNConv,
                                      SimpleConv)
 from repro.core.graph_tensor import (GraphTensor, HIDDEN_STATE, SOURCE,
                                      TARGET)
-from repro.core.graph_update import (GraphUpdate, NextStateFromConcat,
-                                     NodeSetUpdate, SingleInputNextState)
+from repro.core.graph_update import (GraphUpdate, HGTUpdate, MapFeatures,
+                                     NextStateFromConcat, NodeSetUpdate,
+                                     SingleInputNextState)
 from repro.core.schema import GraphSchema
-from repro.nn.layers import Linear
+from repro.nn.layers import Linear, rational_tanh
 from repro.nn.module import Module
 
 
@@ -198,7 +199,9 @@ def hgt_like(edges: Mapping[str, tuple[str, str]],
              node_dims: Mapping[str, int], *, num_heads: int = 4,
              per_head: int = 32, num_rounds: int = 2) -> GNNStack:
     """Heterogeneous transformer-conv stack (the paper's Table-1 competitor
-    family: per-edge-set dot-product attention, per-type projections)."""
+    family: per-edge-set dot-product attention with a softmax and weights
+    per edge set, concatenated into the next state).  Not HGT, whose
+    softmax and weights span relations: see `hgt`."""
     hidden = num_heads * per_head
     updates = []
     for rnd in range(num_rounds):
@@ -218,3 +221,37 @@ def hgt_like(edges: Mapping[str, tuple[str, str]],
                     recv_dim + hidden * len(convs), hidden))
         updates.append(GraphUpdate(node_sets=node_updates))
     return GNNStack(updates)
+
+
+class _TanhLinear(Module):
+    """hidden_state -> tanh(Linear(hidden_state)), with `rational_tanh`."""
+
+    def __init__(self, in_dim: int, units: int):
+        self.dense = Linear(in_dim, units)
+
+    def init(self, key):
+        return self.dense.init(key)
+
+    def __call__(self, params, feats):
+        return dict(feats, **{HIDDEN_STATE: rational_tanh(
+            self.dense(params, feats[HIDDEN_STATE]))})
+
+
+def hgt(edges: Mapping[str, tuple[str, str]],
+        node_dims: Mapping[str, int], *, num_heads: int = 8,
+        per_head: int = 32, num_rounds: int = 3,
+        receiver_tag: str = TARGET,
+        use_layer_norm: bool = True) -> GNNStack:
+    """The Heterogeneous Graph Transformer (Hu et al., WWW 2020).  The
+    stack's first entry (``round_0``) takes each node set's states
+    through tanh(Linear) to num_heads x per_head, as HGT's reference code
+    adapts its inputs; then come `num_rounds` `HGTUpdate`s, each with one
+    softmax per receiver over all of its relations.  No dropout and no
+    relative temporal encoding."""
+    dim = num_heads * per_head
+    inputs = MapFeatures(node_sets={ns: _TanhLinear(d, dim)
+                                    for ns, d in node_dims.items()})
+    return GNNStack([inputs] + [
+        HGTUpdate(edges, dim, num_heads=num_heads,
+                  receiver_tag=receiver_tag, use_layer_norm=use_layer_norm)
+        for _ in range(num_rounds)])

@@ -144,29 +144,58 @@ def pool_edges_to_node(graph: GraphTensor, edge_set_name: str, tag: str,
 
 
 @_scoped("pool")
-def segment_softmax(graph: GraphTensor, edge_set_name: str, tag: str,
+def segment_softmax(graph: GraphTensor, edge_set_name, tag: str,
                     *, feature_value):
     """Softmax of per-edge scores within each receiver node's edge segment
-    (the attention-pooling primitive used by GATv2/transformer convs)."""
-    es = graph.edge_sets[edge_set_name]
-    idx, node_set_name = _edge_endpoint(graph, edge_set_name, tag)
-    num_nodes = graph.node_sets[node_set_name].capacity
-    emask = es.mask()
-    emask_b = emask.reshape(emask.shape + (1,) * (feature_value.ndim - 1))
-    seg_ids = jnp.where(emask, idx, num_nodes)
-    sorted_ids = None if tag == TARGET else False
+    (the attention-pooling primitive used by GATv2/transformer convs).
+
+    `edge_set_name` may also be a sequence of edge sets whose `tag`
+    endpoints are one node set, with `feature_value` a sequence of their
+    scores: then each receiver's softmax runs over its edges in all of
+    them together (one max and one sum over the union, as HGT's Eq. 3),
+    and one coefficient array per edge set is returned, in order."""
+    if isinstance(edge_set_name, str):
+        return _joint_softmax(graph, [edge_set_name], tag,
+                              [feature_value])[0]
+    return _joint_softmax(graph, list(edge_set_name), tag,
+                          list(feature_value))
+
+
+def _joint_softmax(graph: GraphTensor, names: list, tag: str,
+                   values: list) -> list:
+    ends = [_edge_endpoint(graph, name, tag) for name in names]
+    node_sets = {ns for _, ns in ends}
+    if len(node_sets) != 1:
+        raise ValueError(f"edge sets {names} have {tag} endpoints in "
+                         f"{sorted(node_sets)}, not in one node set")
+    num_nodes = graph.node_sets[ends[0][1]].capacity
+    masks = [graph.edge_sets[name].mask() for name in names]
+    masks_b = [m.reshape(m.shape + (1,) * (v.ndim - 1))
+               for m, v in zip(masks, values)]
+    ids = [idx for idx, _ in ends]
+    seg_ids = [jnp.where(m, idx, num_nodes) for m, idx in zip(masks, ids)]
+    # one edge set keeps its layout hint; edges of several are not sorted
+    sorted_ids = None if tag == TARGET and len(names) == 1 else False
+
+    def reduce(parts, reduce_type):
+        if len(parts) == 1:
+            return _mp_segment_reduce(parts[0], seg_ids[0], num_nodes,
+                                      reduce_type, sorted_ids=sorted_ids)
+        return _mp_segment_reduce(
+            jnp.concatenate(parts), jnp.concatenate(seg_ids), num_nodes,
+            reduce_type, sorted_ids=sorted_ids)
+
     # max-shift for stability, then exp-sum — both dispatched reductions
     # (feature-split over the model axis inside a model-parallel trace)
-    seg_max = _mp_segment_reduce(feature_value, seg_ids, num_nodes, "max",
-                                 sorted_ids=sorted_ids)
-    shifted = jnp.where(emask_b,
-                        feature_value - jnp.take(seg_max, idx, axis=0),
-                        -jnp.inf)
-    exp = jnp.where(emask_b, jnp.exp(shifted), 0)
-    seg_sum = _mp_segment_reduce(exp, seg_ids, num_nodes, "sum",
-                                 sorted_ids=sorted_ids)
-    denom = jnp.take(seg_sum, idx, axis=0)
-    return exp / jnp.maximum(denom, 1e-37)
+    seg_max = reduce(values, "max")
+    exps = []
+    for v, mb, idx in zip(values, masks_b, ids):
+        shifted = jnp.where(mb, v - jnp.take(seg_max, idx, axis=0),
+                            -jnp.inf)
+        exps.append(jnp.where(mb, jnp.exp(shifted), 0))
+    seg_sum = reduce(exps, "sum")
+    return [exp / jnp.maximum(jnp.take(seg_sum, idx, axis=0), 1e-37)
+            for exp, idx in zip(exps, ids)]
 
 
 # ---------------------------------------------------------------------------

@@ -97,6 +97,102 @@ def test_segment_softmax_sums_to_one():
                                rtol=1e-5)
 
 
+def _old_segment_softmax(graph, name, tag, value):
+    """`ops.segment_softmax` of one edge set as it was written before it
+    took several: the single-set case must compute exactly this."""
+    es = graph.edge_sets[name]
+    idx = es.adjacency.target if tag == TARGET else es.adjacency.source
+    n = graph.node_sets[es.adjacency.target_name if tag == TARGET
+                        else es.adjacency.source_name].capacity
+    emask = es.mask()
+    emask_b = emask.reshape(emask.shape + (1,) * (value.ndim - 1))
+    seg_ids = jnp.where(emask, idx, n)
+    seg_max = ops._mp_segment_reduce(value, seg_ids, n, "max",
+                                     sorted_ids=None if tag == TARGET
+                                     else False)
+    shifted = jnp.where(emask_b, value - jnp.take(seg_max, idx, axis=0),
+                        -jnp.inf)
+    exp = jnp.where(emask_b, jnp.exp(shifted), 0)
+    seg_sum = ops._mp_segment_reduce(exp, seg_ids, n, "sum",
+                                     sorted_ids=None if tag == TARGET
+                                     else False)
+    return exp / jnp.maximum(jnp.take(seg_sum, idx, axis=0), 1e-37)
+
+
+JOINT = ("purchased", "is-friend")   # both target "users"
+
+
+def _joint_case():
+    g = jax.tree_util.tree_map(jnp.asarray, make_graph(pad_edges=4))
+    rng = np.random.default_rng(1)
+    scores = [jnp.asarray(rng.normal(size=(g.edge_sets[n].capacity, 2))
+                          .astype(np.float32)) for n in JOINT]
+    return g, scores
+
+
+@pytest.mark.parametrize("case", ["sums_to_one", "equals_concatenated",
+                                  "padding_gets_zero", "one_set_is_today",
+                                  "differs_from_per_set"])
+def test_joint_segment_softmax(case):
+    """One softmax over every edge into a node across several edge sets
+    (HGT's Eq. 3), two heads of scores."""
+    g, scores = _joint_case()
+    coefs = ops.segment_softmax(g, list(JOINT), TARGET,
+                                feature_value=scores)
+    n_users = g.node_sets["users"].capacity
+    valid = [np.asarray(g.edge_sets[n].mask()) for n in JOINT]
+    tgts = [np.asarray(g.edge_sets[n].adjacency.target) for n in JOINT]
+    c = [np.asarray(x) for x in coefs]
+    if case == "sums_to_one":
+        sums = np.zeros((n_users, 2))
+        for ci, t, v in zip(c, tgts, valid):
+            np.add.at(sums, t[v], ci[v])
+        has = np.zeros(n_users, bool)
+        for t, v in zip(tgts, valid):
+            has[t[v]] = True
+        # node 0 and others hear over both sets in this graph
+        assert has.sum() >= 3
+        np.testing.assert_allclose(sums[has], 1.0, rtol=1e-6)
+        np.testing.assert_array_equal(sums[~has], 0.0)
+    elif case == "equals_concatenated":
+        s = np.concatenate([np.asarray(x)[v] for x, v in zip(scores, valid)])
+        t = np.concatenate([t[v] for t, v in zip(tgts, valid)])
+        want = np.zeros_like(s)
+        for u in np.unique(t):
+            e = np.exp(s[t == u] - s[t == u].max(0))
+            want[t == u] = e / e.sum(0)
+        got = np.concatenate([ci[v] for ci, v in zip(c, valid)])
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+    elif case == "padding_gets_zero":
+        assert (~valid[0]).sum() == 4
+        np.testing.assert_array_equal(c[0][~valid[0]], 0.0)
+    elif case == "one_set_is_today":
+        for name, x in zip(JOINT, scores):
+            one = ops.segment_softmax(g, name, TARGET, feature_value=x)
+            np.testing.assert_array_equal(
+                np.asarray(one),
+                np.asarray(_old_segment_softmax(g, name, TARGET, x)))
+            listed = ops.segment_softmax(g, [name], TARGET,
+                                         feature_value=[x])
+            np.testing.assert_array_equal(np.asarray(listed[0]),
+                                          np.asarray(one))
+    else:
+        apart = [np.asarray(ops.segment_softmax(g, n, TARGET,
+                                                feature_value=x))
+                 for n, x in zip(JOINT, scores)]
+        both = set(tgts[0][valid[0]]) & set(tgts[1][valid[1]])
+        assert both, "no user hears over both edge sets"
+        for ci, a, t, v in zip(c, apart, tgts, valid):
+            shared = v & np.isin(t, list(both))
+            assert np.all(ci[shared] < a[shared])
+
+
+def test_joint_softmax_needs_one_receiver_set():
+    g, scores = _joint_case()
+    with pytest.raises(ValueError, match="not in one node set"):
+        ops.segment_softmax(g, list(JOINT), SOURCE, feature_value=scores)
+
+
 def test_context_ops_roundtrip(graph):
     total = ops.pool_nodes_to_context(graph, "users", "sum",
                                       feature_name="h")

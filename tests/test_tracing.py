@@ -7,8 +7,8 @@ import re
 import jax
 import pytest
 
-from repro.core import HIDDEN_STATE, mag_schema
-from repro.core.models import vanilla_mpnn
+from repro.core import HIDDEN_STATE, SOURCE, mag_schema
+from repro.core.models import hgt, vanilla_mpnn
 from repro.data import (GraphBatcher, InMemorySampler, SamplingSpecBuilder,
                         find_size_constraints)
 from repro.data.synthetic import synthetic_mag
@@ -56,6 +56,14 @@ def model_fn():
     return Init(), gnn
 
 
+def hgt_model_fn():
+    """`model_fn`'s initial states, then a 2-round HGT of 2 heads."""
+    init, _ = model_fn()
+    return init, hgt({"cites": ("paper", "paper")}, {"paper": DIM},
+                     num_heads=2, per_head=DIM // 2, num_rounds=2,
+                     receiver_tag=SOURCE)
+
+
 def task():
     return RootNodeMulticlassClassification("paper", 4, DIM)
 
@@ -78,7 +86,7 @@ def metadata_in_cache_key():
                           before)
 
 
-def step_hlo(problem, monkeypatch) -> str:
+def step_hlo(problem, monkeypatch, model=model_fn) -> str:
     """The optimized HLO of the Trainer's train step on `problem`."""
     _, _, _, graphs, sizes = problem
     got = {}
@@ -96,7 +104,7 @@ def step_hlo(problem, monkeypatch) -> str:
 
     with monkeypatch.context() as m, metadata_in_cache_key():
         m.setattr(trainer_mod, "make_graph_train_step", make_captured)
-        trainer(max_steps=1).fit(model_fn, task(),
+        trainer(max_steps=1).fit(model, task(),
                                  BatcherProvider(graphs, 8, sizes, seed=0))
         # the executable that ran, from JAX's in-memory cache
         return got["step"].lower(*got["shapes"]).compile().as_text()
@@ -123,6 +131,23 @@ def test_scopes_leave_the_optimized_step_unchanged(problem, monkeypatch):
         assert any(stack in n for n in names), stack
     assert not any(re.search(r"round_0|optimizer", n) for n in
                    re.findall(r'op_name="([^"]*)"', plain))
+
+
+def test_hgt_scopes_in_step_hlo(problem, monkeypatch):
+    """HGT's attention (scores, joint softmax, weighted pool) and its
+    relation transforms carry their own scopes, under the round's and
+    the receiving set's, in both passes; the stack's first entry is the
+    input adaptation."""
+    names = set(re.findall(r'op_name="([^"]*)"',
+                           step_hlo(problem, monkeypatch, hgt_model_fn)))
+    for stack in ("jvp(gnn)/round_0/", "jvp(gnn)/round_1/paper/hgt_relation/",
+                  "jvp(gnn)/round_2/paper/hgt_attention/",
+                  "jvp(gnn)/round_1/paper/hgt_attention/pool/",
+                  "transpose(jvp(gnn))/round_2/paper/hgt_attention/",
+                  "transpose(jvp(gnn))/round_1/paper/hgt_relation/"):
+        assert any(stack in n for n in names), stack
+    # round_0 is the input adaptation, which attends to nothing
+    assert not any(re.search(r"round_0/.*hgt_", n) for n in names)
 
 
 def traced_spans(provider, log_dir, steps: int) -> list:
