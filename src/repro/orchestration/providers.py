@@ -42,7 +42,7 @@ from repro.data.batching import SizeConstraints
 from repro.data.grouping import (BatchPlan, build_batch,
                                  step_size_constraints)
 from repro.data.pipeline import GraphBatcher
-from repro.data.sampling import (GraphStore, SamplingSpec, sample_subgraph,
+from repro.data.sampling import (GraphStore, SamplingSpec, sample_subgraphs,
                                  seed_rng)
 
 
@@ -176,9 +176,10 @@ class StoreProvider(DatasetProvider):
             idx = self.plan.step_indices(order, step)
             with jax.profiler.TraceAnnotation("repro.sample", epoch=epoch,
                                               step=step):
-                graphs = [sample_subgraph(self.store, self.spec, int(r),
-                                          seed_rng(self.base_seed, int(r)))
-                          for r in (self.roots[i] for i in idx)]
+                roots = self.roots[idx]
+                graphs = sample_subgraphs(
+                    self.store, self.spec, roots,
+                    [seed_rng(self.base_seed, int(r)) for r in roots])
             with jax.profiler.TraceAnnotation("repro.merge_pad",
                                               epoch=epoch, step=step):
                 batch = build_batch(graphs, self.plan, sizes)

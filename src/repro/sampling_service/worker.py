@@ -27,7 +27,7 @@ import numpy as np
 from repro.data.batching import SizeConstraints
 from repro.data.grouping import (BatchPlan, build_batch,
                                  step_size_constraints)
-from repro.data.sampling import (GraphStore, SamplingSpec, sample_subgraph,
+from repro.data.sampling import (GraphStore, SamplingSpec, sample_subgraphs,
                                  seed_rng)
 from repro.sampling_service import wire
 
@@ -89,11 +89,10 @@ class SamplerWorker:
         if self._order is None or epoch != self._epoch:
             self._epoch, self._order = epoch, self.plan.order(
                 epoch, len(self.seeds))
-        idx = self.plan.step_indices(self._order, step)
-        graphs = [
-            sample_subgraph(self.store, self.spec, int(self.seeds[i]),
-                            seed_rng(self.base_seed, int(self.seeds[i])))
-            for i in idx]
+        roots = self.seeds[self.plan.step_indices(self._order, step)]
+        graphs = sample_subgraphs(
+            self.store, self.spec, roots,
+            [seed_rng(self.base_seed, int(r)) for r in roots])
         return build_batch(graphs, self.plan, self.sizes)
 
     def serve_forever(self) -> None:

@@ -220,12 +220,12 @@ class MmapGraphStore(GraphStore):
     reads and sampling touches only the pages it actually slices.
 
     Satisfies the full `GraphStore` interface — `neighbors` /
-    `neighbors_batch` / `gather_node_features` / `.edges` /
-    `.node_features` — so `sample_subgraph`, `InMemorySampler`, sampler
-    workers, and `VersionedGraphStore.wrap` run unmodified.  `_reindex`
-    is free for untouched edge sets (the on-disk indptr IS the index);
-    it falls back to the in-memory rebuild only for edge sets mutated
-    through `.edges`.
+    `neighbors_batch` / `neighbors_flat` / `gather_node_features` /
+    `.edges` / `.node_features` — so `sample_subgraphs`,
+    `InMemorySampler`, sampler workers, and `VersionedGraphStore.wrap`
+    run unmodified.  `_reindex` is free for untouched edge sets (the
+    on-disk indptr IS the index); it falls back to the in-memory rebuild
+    only for edge sets mutated through `.edges`.
 
     `gather_chunk_rows` turns on the bounded-RSS gather path: feature
     gathers copy at most that many rows between MADV_DONTNEED calls,
@@ -309,12 +309,21 @@ class MmapGraphStore(GraphStore):
     def neighbors_batch(self, edge_set: str,
                         nodes) -> list[np.ndarray]:
         result = super().neighbors_batch(edge_set, nodes)
+        self._drop_edge_pages(edge_set)
+        return result
+
+    def neighbors_flat(self, edge_set: str,
+                       nodes) -> tuple[np.ndarray, np.ndarray]:
+        result = super().neighbors_flat(edge_set, nodes)
+        self._drop_edge_pages(edge_set)
+        return result
+
+    def _drop_edge_pages(self, edge_set: str) -> None:
         if self.gather_chunk_rows is not None:
             # views into the mapping survive the drop (they refault
             # from page cache); only this process's RSS is released
             _madv_dontneed(self._indptr.get(edge_set))
             _madv_dontneed(self._indices.get(edge_set))
-        return result
 
     def drop_page_cache(self) -> None:
         """Release every mapped page from this process's RSS (the files

@@ -123,10 +123,7 @@ class GraphShardServer:
                 payload) -> None:
         if kind == wire.NBR:
             nodes = np.asarray(payload["nodes"], np.int64)
-            nbrs = self.store.neighbors_batch(meta["edge_set"], nodes)
-            counts = np.asarray([len(x) for x in nbrs], np.int64)
-            flat = (np.concatenate(nbrs).astype(np.int64, copy=False)
-                    if nbrs else np.zeros(0, np.int64))
+            counts, flat = self.store.neighbors_flat(meta["edge_set"], nodes)
             reply = (wire.NBRS, {"counts": counts, "neighbors": flat})
         elif kind == wire.FEAT:
             nodes = np.asarray(payload["nodes"], np.int64)
@@ -319,6 +316,14 @@ class ShardedGraphStore(GraphStore):
                 out[i] = arr
                 self._cache.put((edge_set, int(nodes[i])), arr)
         return out
+
+    def neighbors_flat(self, edge_set: str, nodes: Sequence[int]
+                       ) -> tuple[np.ndarray, np.ndarray]:
+        nbrs = self.neighbors_batch(edge_set, nodes)
+        counts = np.asarray([len(x) for x in nbrs], np.int64)
+        flat = (np.concatenate(nbrs).astype(np.int64, copy=False)
+                if nbrs else np.zeros(0, np.int64))
+        return counts, flat
 
     def gather_node_features(self, node_set: str,
                              ids: np.ndarray) -> dict[str, np.ndarray]:

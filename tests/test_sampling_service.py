@@ -138,8 +138,13 @@ def test_stream_bit_identity_either_edge_layout(problem, sort_bit):
     """The service and the in-process batcher stay bit-identical with
     edges sorted by target (the new default) AND with the opt-out — the
     layout bit is part of the shared BatchPlan contract, not a
-    service-side transform."""
-    store, spec, roots, graphs, sizes = problem
+    service-side transform.  The batcher runs over the frozen per-root
+    sampler's subgraphs, which the workers' batched sampling matches."""
+    from repro.data.sampling import seed_rng
+    from test_sampling import frozen_sample_subgraph
+    store, spec, roots, _, sizes = problem
+    graphs = [frozen_sample_subgraph(store, spec, r, seed_rng(0, r))
+              for r in roots]
     batcher = GraphBatcher(graphs, 16, sizes, seed=0, num_replicas=2,
                            edges_sorted_by_target=sort_bit)
     assert batcher.plan.edges_sorted_by_target is sort_bit

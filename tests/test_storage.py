@@ -14,7 +14,8 @@ from repro.core.schema import (EdgeSetSpec, FeatureSpec, GraphSchema,
 from repro.data import InMemorySampler, SamplingSpecBuilder, \
     find_size_constraints
 from repro.data.grouping import BatchPlan, build_batch
-from repro.data.sampling import GraphStore, sample_subgraph, seed_rng
+from repro.data.sampling import (GraphStore, sample_subgraph,
+                                 sample_subgraphs, seed_rng)
 from repro.data.synthetic import synthetic_mag
 from repro.serve.cache import VersionedGraphStore
 from repro.storage import (FORMAT_NAME, MmapGraphStore, graph_bytes,
@@ -176,16 +177,31 @@ def _flat(g):
 
 
 def test_subgraphs_bit_identical(mag_problem):
+    """The mmap store's `neighbors_flat` (page drops on or off) answers
+    as the in-memory store's, and sampling through it, root by root or
+    all roots in one call, gives the in-memory store's subgraphs."""
     store, spec, path = mag_problem
-    m = MmapGraphStore(path)
-    for root in range(32):
-        a = sample_subgraph(store, spec, root, seed_rng(0, root))
-        b = sample_subgraph(m, spec, root, seed_rng(0, root))
-        fa, fb = _flat(a), _flat(b)
-        assert fa.keys() == fb.keys()
-        for k in fa:
-            np.testing.assert_array_equal(
-                np.asarray(fa[k]), np.asarray(fb[k]), err_msg=k)
+    every = np.arange(store.num_nodes["paper"])[::-1]
+    roots = np.random.default_rng(0).permutation(32)
+    for gather_chunk_rows in (None, 5):
+        m = MmapGraphStore(path, gather_chunk_rows=gather_chunk_rows)
+        for name in ("cites", "written"):
+            for x, y in zip(m.neighbors_flat(name, every),
+                            store.neighbors_flat(name, every)):
+                assert x.dtype == y.dtype == np.int64
+                np.testing.assert_array_equal(x, y)
+        batched = sample_subgraphs(m, spec, roots,
+                                   [seed_rng(0, r) for r in roots])
+        for root, c in zip(roots, batched):
+            a = sample_subgraph(store, spec, int(root), seed_rng(0, root))
+            b = sample_subgraph(m, spec, int(root), seed_rng(0, root))
+            fa = _flat(a)
+            for other in (b, c):
+                fb = _flat(other)
+                assert fa.keys() == fb.keys()
+                for k in fa:
+                    np.testing.assert_array_equal(
+                        np.asarray(fa[k]), np.asarray(fb[k]), err_msg=k)
 
 
 def test_batches_bit_identical_with_plan_bit(mag_problem):

@@ -222,6 +222,19 @@ def test_sharded_store_lru_and_fallback(problem):
             np.testing.assert_array_equal(a, b)
             np.testing.assert_array_equal(a, store.neighbors("cites",
                                                              int(u)))
+        # the flat hook over local, remote and cached lookups, and the
+        # batched sampler through it, answer as the in-memory store
+        mixed = np.concatenate([remote, np.arange(n // 2 - 20, n // 2 + 20,
+                                                  dtype=np.int64)])[::-1]
+        remote_before = sh.stats["remote"]
+        for x, y in zip(sh.neighbors_flat("cites", mixed),
+                        store.neighbors_flat("cites", mixed)):
+            np.testing.assert_array_equal(x, y)
+        assert sh.stats["remote"] > remote_before
+        sampled = InMemorySampler(sh, spec, seed=0).sample(roots)
+        want = InMemorySampler(store, spec, seed=0).sample(roots)
+        for g, w in zip(sampled, want):
+            assert_graphs_equal(g, w)
         # peer death -> local fallback, identical answers
         server.close()
         fresh = np.arange(n - 20, n - 8, dtype=np.int64)  # uncached
